@@ -61,9 +61,11 @@ cost-per-round — under the diurnal profile. Lands in
 ``BENCH_traffic.json``; exits nonzero if the bulk path diverges from the
 per-event oracle (the CI gate).
 
-``--sharding`` measures the mesh plane (DESIGN.md §15): a weak-scaling
+``--sharding`` is a CPU-only layout check of the mesh plane (DESIGN.md
+§15; the chip's mesh path is ``chip_smoke.py --chips 4``): a weak-scaling
 curve over the ("data", "model") device mesh — each cell is a subprocess
-with its own forced host-device count (XLA fixes the count at startup),
+on the CPU backend with its own forced host-device count (XLA fixes the
+count at startup),
 cohort size growing with the data axis, wall rounds/s plus the
 structural metrics (bottleneck-device update-store bytes, equal-tile
 split). Lands in ``BENCH_sharding.json``; exits nonzero if mesh='1x1'
@@ -845,6 +847,9 @@ def _sharding_cell(spec: str, n_dev: int, K: int, rounds: int,
     env.pop("XLA_FLAGS", None)
     env.pop("REPRO_MESH", None)
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    # forced host devices exist only on the CPU backend; on a chip host the
+    # parent already holds the accelerator, which a child could not open
+    env["JAX_PLATFORMS"] = "cpu"
     env["REPRO_SH_MESH"] = spec
     env["REPRO_SH_DEVICES"] = str(n_dev)
     env["REPRO_SH_K"] = str(K)
@@ -858,7 +863,10 @@ def _sharding_cell(spec: str, n_dev: int, K: int, rounds: int,
 
 
 def run_sharding(smoke: bool = False, json_path: str = "") -> dict:
-    """Sharded-plane bench (DESIGN.md §15): a weak-scaling curve over the
+    """Sharded-plane bench (DESIGN.md §15), a CPU-only layout check: every
+    cell runs in a child process on forced host devices
+    (``JAX_PLATFORMS=cpu``). The mesh path on real chips is
+    ``python chip_smoke.py --chips 4``. A weak-scaling curve over the
     ("data", "model") mesh — cohort size K grows with the data axis, so
     ideal scaling holds wall time per round flat while client-updates/s
     grows with the device count. On a host with fewer cores than forced
@@ -1384,6 +1392,9 @@ def run_durability(smoke: bool = False, json_path: str = "") -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     smoke = "--smoke" in sys.argv
     jp = ""
     if "--json" in sys.argv:

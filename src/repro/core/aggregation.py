@@ -19,17 +19,20 @@ the paper's serverless aggregation function. Three execution paths
     N padded to the kernel block), then reduced by the fused
     ``kernels/staleness_agg.py`` multiply-accumulate kernel — interpret mode
     on CPU/GPU, compiled Mosaic on TPU. A one-time numerical-equivalence
-    self-check against the XLA path gates the dispatch; any mismatch or
-    kernel failure falls back to XLA for the rest of the process.
+    self-check against a float64 reference runs before the first kernel
+    dispatch; a mismatch raises, and so does any kernel error: a broken
+    kernel fails the run instead of quietly moving it to XLA.
   * **XLA** (``_weighted_sum_stacked``): jit'd per-leaf stacked reduction.
-    Fallback path, and forced via ``path="xla"`` or ``REPRO_AGG_PATH=xla``.
+    Taken off-TPU above the interpret-mode size cap, and forced via
+    ``path="xla"`` or ``REPRO_AGG_PATH=xla``.
   * **Sharded**: on a mesh, stacked updates [K, ...] are sharded over the
     ``pod``/``data`` axes and the reduce lowers to a weighted psum — this is
     how the FaaS aggregation pattern maps onto TPU collectives (DESIGN.md §4).
 
 Dispatch policy: ``path`` argument > ``REPRO_AGG_PATH`` env var > ``auto``
-(Pallas when the self-check passes, XLA otherwise). ``last_path()`` reports
-which path produced the most recent result (observability + tests).
+(Pallas on TPU, and off-TPU up to the interpret-mode size cap).
+``last_path()`` reports which path produced the most recent result
+(observability + tests).
 
 ``weighted_aggregate`` consumes a *list of pytrees* (the legacy blob path).
 ``weighted_aggregate_rows`` is the device-resident update-plane fast path:
@@ -52,7 +55,7 @@ from repro.kernels.staleness_agg import BLOCK_N
 
 Pytree = Any
 
-_PALLAS_OK: Optional[bool] = None   # equivalence self-check; False = disabled
+_PALLAS_CHECKED = False   # the equivalence self-check has passed
 _LAST_PATH = "none"
 # In interpret mode (no TPU) the kernel is a correctness path, ~100x slower
 # than XLA at large N; ``auto`` only takes it below this parameter count.
@@ -95,32 +98,41 @@ def _weighted_sum_stacked(stacked: Pytree, weights: jax.Array) -> Pytree:
 # The ravel -> [K, N] buffer -> kernel -> unravel plumbing (including the
 # sublane/block padding) lives in kernels/ops.aggregate_pytree; this module
 # only owns the dispatch policy around it.
-def _pallas_validated() -> bool:
-    """One-time numerical-equivalence check of the kernel path vs. XLA.
+def _check_pallas() -> None:
+    """One-time numerical-equivalence check of the kernel path against a
+    float64 reference, run before the first kernel dispatch.
 
     Runs a deterministic ragged pytree that exercises both pad paths (K not
-    a sublane multiple, N not a block multiple). On mismatch or any kernel
-    error the process permanently falls back to XLA."""
-    global _PALLAS_OK
-    if _PALLAS_OK is None:
-        try:
-            rng = np.random.default_rng(0)
-            ups = [{"a": jnp.asarray(rng.normal(size=(BLOCK_N,)), jnp.float32),
-                    "b": jnp.asarray(rng.normal(size=(17,)), jnp.float32)}
-                   for _ in range(3)]
-            w = staleness_weights([2, 1, 0], [5, 3, 2], 2)
-            got = kernel_ops.aggregate_pytree(ups, w, restore_dtype=False)
-            stack = {k: np.stack([np.asarray(u[k], np.float64) for u in ups])
-                     for k in ("a", "b")}
-            w64 = np.asarray(w, np.float64)
-            _PALLAS_OK = all(
-                np.allclose(np.asarray(got[k]),
-                            np.einsum("k,kn->n", w64, stack[k]),
-                            rtol=1e-5, atol=1e-6)
-                for k in ("a", "b"))
-        except Exception:  # noqa: BLE001 — any kernel failure disables path
-            _PALLAS_OK = False
-    return _PALLAS_OK
+    a sublane multiple, N not a block multiple). A mismatch raises; so does
+    a kernel that fails to compile or run."""
+    global _PALLAS_CHECKED
+    if _PALLAS_CHECKED:
+        return
+    rng = np.random.default_rng(0)
+    ups = [{"a": jnp.asarray(rng.normal(size=(BLOCK_N,)), jnp.float32),
+            "b": jnp.asarray(rng.normal(size=(17,)), jnp.float32)}
+           for _ in range(3)]
+    w = staleness_weights([2, 1, 0], [5, 3, 2], 2)
+    got = kernel_ops.aggregate_pytree(ups, w, restore_dtype=False)
+    w64 = np.asarray(w, np.float64)
+    for k in ("a", "b"):
+        want = np.einsum("k,kn->n", w64,
+                         np.stack([np.asarray(u[k], np.float64) for u in ups]))
+        if not np.allclose(np.asarray(got[k]), want, rtol=1e-5, atol=1e-6):
+            raise RuntimeError(
+                "staleness_agg kernel disagrees with the float64 reference "
+                f"on {jax.default_backend()} (leaf {k!r})")
+    _PALLAS_CHECKED = True
+
+
+def _use_pallas(path: str, n_params: int) -> bool:
+    """The kernel route: forced, or ``auto`` on TPU (and off-TPU up to the
+    interpret-mode size cap). Runs the self-check before the first use."""
+    use = path == "pallas" or (path == "auto" and (
+        kernel_ops.on_tpu() or n_params <= _INTERP_MAX_N))
+    if use:
+        _check_pallas()
+    return use
 
 
 # --------------------------------------------------------------- dispatch
@@ -129,34 +141,23 @@ def weighted_aggregate(updates: Sequence[Pytree], weights: np.ndarray,
     """updates: list of K pytrees -> weighted average pytree (fp32 leaves
     unless ``out_dtype`` is given).
 
-    ``path``: "auto" (default — Pallas kernel when its equivalence
-    self-check passes; off-TPU the interpreter is only taken up to
-    ``REPRO_AGG_PALLAS_MAX_INTERP_N`` params), "pallas" (force kernel;
-    raises on failure), or "xla". ``REPRO_AGG_PATH`` overrides the
-    default."""
+    ``path``: "auto" (default — the Pallas kernel; off-TPU the interpreter
+    is only taken up to ``REPRO_AGG_PALLAS_MAX_INTERP_N`` params),
+    "pallas" (force kernel), or "xla". ``REPRO_AGG_PATH`` overrides the
+    default. Kernel errors propagate."""
     global _LAST_PATH
     assert len(updates) == len(weights) and len(updates) > 0
     path = path or os.environ.get("REPRO_AGG_PATH", "auto")
     if path not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown aggregation path {path!r}")
 
-    global _PALLAS_OK
     n_params = sum(int(np.prod(l.shape)) if l.shape else 1
                    for l in jax.tree.leaves(updates[0]))
-    auto_pallas = (_pallas_validated()
-                   and (kernel_ops.on_tpu() or n_params <= _INTERP_MAX_N))
-    out = None
-    if path == "pallas" or (path == "auto" and auto_pallas):
-        try:
-            out = kernel_ops.aggregate_pytree(updates, weights,
-                                              restore_dtype=False)
-            _LAST_PATH = "pallas"
-        except Exception:  # noqa: BLE001 — fall back unless forced
-            if path == "pallas":
-                raise
-            _PALLAS_OK = False  # runtime failure: disable for the process
-            out = None
-    if out is None:
+    if _use_pallas(path, n_params):
+        out = kernel_ops.aggregate_pytree(updates, weights,
+                                          restore_dtype=False)
+        _LAST_PATH = "pallas"
+    else:
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs, 0), *updates)
         out = _weighted_sum_stacked(stacked, jnp.asarray(weights))
         _LAST_PATH = "xla"
@@ -177,7 +178,8 @@ def weighted_aggregate_rows(buffer, row_idx, weights,
     ravel, no stack, no per-leaf work — and the flat result unravels exactly
     once to produce the new global pytree. Dispatch policy (``path`` arg,
     ``REPRO_AGG_PATH``, self-check, interpret-mode size cap) is identical to
-    ``weighted_aggregate``.
+    ``weighted_aggregate``; so is the error policy: kernel errors
+    propagate.
 
     With ``mesh`` set (the buffer sharded P("data", "model")), the
     reduction routes to ``kernels/ops.aggregate_rows_psum``: a weighted
@@ -200,32 +202,22 @@ def weighted_aggregate_rows(buffer, row_idx, weights,
             out = jax.tree.map(lambda x: x.astype(out_dtype), out)
         return out
 
-    global _PALLAS_OK
-    auto_pallas = (_pallas_validated()
-                   and (kernel_ops.on_tpu()
-                        or spec.n_params <= _INTERP_MAX_N))
     # The full-buffer sweep reads every row; once the reference set is a
     # small fraction of a grown buffer (capacity only doubles, never
     # shrinks), gathering just the K referenced rows is cheaper — and
     # needs no finiteness guard, since it never touches freed rows.
     sparse = (path != "pallas"
               and buffer.shape[0] >= 4 * max(len(row_idx), kernel_ops.SUBLANE))
-    flat = None
-    if sparse:
+    use_pallas = _use_pallas(path, spec.n_params)
+    if sparse and use_pallas:
+        flat = kernel_ops.aggregate_rows_sparse(buffer, row_idx, weights)
+    elif sparse:
         flat = kernel_ops.aggregate_rows_gather(buffer, row_idx, weights)
-        _LAST_PATH = "xla"
-    elif path == "pallas" or (path == "auto" and auto_pallas):
-        try:
-            flat = kernel_ops.aggregate_rows(buffer, row_idx, weights)
-            _LAST_PATH = "pallas"
-        except Exception:  # noqa: BLE001 — fall back unless forced
-            if path == "pallas":
-                raise
-            _PALLAS_OK = False  # runtime failure: disable for the process
-            flat = None
-    if flat is None:
+    elif use_pallas:
+        flat = kernel_ops.aggregate_rows(buffer, row_idx, weights)
+    else:
         flat = kernel_ops.aggregate_rows_xla(buffer, row_idx, weights)
-        _LAST_PATH = "xla"
+    _LAST_PATH = "pallas" if use_pallas else "xla"
     # Finiteness guard: the full-buffer sweep multiplies freed rows by
     # weight 0, which is only exact for finite stale values (0 * inf = nan).
     # A non-finite result triggers one exact recompute over just the
@@ -250,19 +242,13 @@ def rows_dispatch(buffer_rows: int, k: int, n_params: int,
     facts only (buffer capacity, K, model size, env/path policy). The
     expressions here are verbatim from ``weighted_aggregate_rows`` —
     keeping them in this module means a policy change cannot silently
-    fork the two paths. The one dynamic behavior that cannot be
-    replicated in-trace is the Pallas runtime-raise fallback
-    (``_PALLAS_OK`` flipping False mid-process); a trace-time raise
-    simply aborts megastep entry and the round runs stepwise."""
+    fork the two paths."""
     path = path or os.environ.get("REPRO_AGG_PATH", "auto")
     if path not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown aggregation path {path!r}")
     sparse = (path != "pallas"
               and buffer_rows >= 4 * max(k, kernel_ops.SUBLANE))
-    use_pallas = (path == "pallas"
-                  or (path == "auto" and _pallas_validated()
-                      and (kernel_ops.on_tpu()
-                           or n_params <= _INTERP_MAX_N)))
+    use_pallas = _use_pallas(path, n_params)
     return sparse, use_pallas, kernel_ops.default_interpret()
 
 

@@ -194,15 +194,13 @@ class CohortTrainer:
                 # produces — only aggregation reassociates floats.
                 #
                 # The [Kp, 2] lane-key table enters REPLICATED (P()) and
-                # each shard slices its own lane block below. Consuming it
-                # P("data") would let GSPMD shard the *producing*
-                # ``jax.random.split`` when the keys are computed inside
-                # the same program (the fused megastep scan) — and with
-                # the non-partitionable threefry default that silently
-                # changes the key values, breaking the fused/stepwise
-                # bit-identity contract. Eager callers are unaffected
-                # either way (their keys are concrete before the jit).
-                from jax.experimental.shard_map import shard_map
+                # each shard slices its own lane block below, so the key
+                # values never depend on how the partitioner splits a
+                # ``jax.random.split`` traced in the same program (the
+                # fused megastep scan). With ``jax_threefry_partitionable``
+                # off (on by default in the installed JAX) a sharded split
+                # would change them and break the fused/stepwise
+                # bit-identity contract.
                 from jax.sharding import PartitionSpec as P
                 vv = v
 
@@ -214,12 +212,12 @@ class CohortTrainer:
                     return vv(params0, cidx, n_i, steps, keys_l, cg, ci,
                               DX, Dy)
 
-                v = shard_map(
+                v = jax.shard_map(
                     _shard_body, mesh=self.mesh,
                     in_specs=(P(), P("data"), P("data"), P("data"),
                               P(), P(), P("data"), P(), P()),
                     out_specs=(P("data"), P("data"), P("data")),
-                    check_rep=False)
+                    check_vma=False)
             n_lead = 9
         else:
             def client_fn(params0, X, y, n_i, steps, key, cg, ci):

@@ -268,11 +268,8 @@ def _plan(sched) -> tuple[Optional[MegastepPlan], str]:
     from repro.core.aggregation import rows_dispatch
     from repro.core.scoring import promotion_rate
 
-    try:
-        fn, Kp, max_steps = sched.trainer.cohort_fn_indexed(
-            sched.dataset, K, int(steps_col[slots].max()))
-    except Exception:  # noqa: BLE001 — e.g. forced-pallas trace failure
-        return None, "cohort fn compilation failed"
+    fn, Kp, max_steps = sched.trainer.cohort_fn_indexed(
+        sched.dataset, K, int(steps_col[slots].max()))
     if stack.size < Kp:
         return None, "update-store free list too small (would grow)"
     try:
@@ -341,19 +338,6 @@ def _build_scan(plan: MegastepPlan, spec):
               card32_col, upd32_col, d32_col, rank_col,
               beta32, dec32):
 
-        # Cohort-bucket pad maps, resolved at trace time: lane k >= K
-        # repeats lane K-1's client and runs 0 steps — the values
-        # _cohort_pad/train_cohort_indexed produce. Under a mesh these are
-        # applied as constant-map GATHERS rather than the stepwise path's
-        # concatenate-of-repeated-slice: that concatenate pattern is
-        # miscompiled by the 0.4.x SPMD partitioner when a shard_map
-        # coexists in the program (a spurious model-axis all-reduce scales
-        # the values; see kernels.ops.aggregate_rows_traced). The gather
-        # form produces bitwise the same integers on any mesh.
-        pad_map = np.concatenate([np.arange(K), np.full(Kp - K, K - 1)]
-                                 ).astype(np.int32)
-        step_mask = np.concatenate([np.ones(K, bool), np.zeros(Kp - K, bool)])
-
         def body(carry, _):
             params, buffer, stack, num, den, booster, key = carry
             # -- selection: the exact select_topk program ------------------
@@ -362,11 +346,9 @@ def _build_scan(plan: MegastepPlan, spec):
             # -- update rows: the UpdateStore LIFO pop sequence ------------
             ids = stack[top - Kp:top][::-1]
             # -- cohort train: same compiled fn, same padding, same keys ---
-            if Kp > K and mesh is not None:
-                sel_p = sel[jnp.asarray(pad_map)]
-                steps_p = jnp.where(jnp.asarray(step_mask),
-                                    steps_col[sel_p], 0)
-            elif Kp > K:
+            # (lane k >= K repeats lane K-1's client and runs 0 steps — the
+            # values _cohort_pad/train_cohort_indexed produce)
+            if Kp > K:
                 sel_p = jnp.concatenate([sel, jnp.repeat(sel[-1:], Kp - K)])
                 steps_sel = steps_col[sel]
                 steps_p = jnp.concatenate(
@@ -401,14 +383,8 @@ def _build_scan(plan: MegastepPlan, spec):
             out = spec.unravel(flat[:spec.n_params], restore_dtype=False)
             params = jax.tree.map(lambda x: x.astype(out_dtype), out)
             # -- free-stack push algebra (pad frees, then landing frees) ---
-            if mesh is not None:
-                # two static-slice writes instead of a concatenate (same
-                # SPMD-partitioner hazard as the pad maps above)
-                stack = stack.at[top - Kp:top - K].set(ids[K:])
-                stack = stack.at[top - K:top].set(rows_land)
-            else:
-                stack = stack.at[top - Kp:top].set(
-                    jnp.concatenate([ids[K:], rows_land]))
+            stack = stack.at[top - Kp:top].set(
+                jnp.concatenate([ids[K:], rows_land]))
             return ((params, buffer, stack, num, den, booster, key),
                     (sel, ids, losses[:K]))
 

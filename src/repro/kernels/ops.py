@@ -13,7 +13,9 @@ Higher-level conveniences:
     parameter pytrees (ravel -> kernel -> unravel), the drop-in kernel path
     for ``repro.core.aggregation``;
   - ``aggregate_rows``: index-gather entry point over a persistent [C, N]
-    row buffer (the update-plane hot path — no ravel, no stack);
+    row buffer (the update-plane hot path — no ravel, no stack), and
+    ``aggregate_rows_sparse``, its route for a buffer grown far past the
+    live set (gather the K referenced rows, then the kernel);
   - ``masked_topk``: top-k of a score vector (the control plane's cohort
     selection) — XLA ``lax.top_k`` fast path, blockwise Pallas kernel on
     TPU (``REPRO_TOPK_PATH=pallas|xla|auto`` forcing);
@@ -160,6 +162,31 @@ def _gather_weighted_sum(buffer: jax.Array, idx: jax.Array,
                       buffer[idx].astype(jnp.float32))
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _gather_agg(buffer: jax.Array, idx: jax.Array, w: jax.Array,
+                interpret: bool) -> jax.Array:
+    """Gather the (sublane-padded) referenced rows, then reduce them with
+    the kernel: the kernel twin of ``_gather_weighted_sum``."""
+    N = buffer.shape[1]
+    rows = buffer[idx].astype(jnp.float32)
+    pad_n = (-N) % BLOCK_N
+    if pad_n:   # non-conforming caller buffer: pad (copies)
+        rows = jnp.pad(rows, ((0, 0), (0, pad_n)))
+    return staleness_agg(rows, w, interpret=interpret)[:N]
+
+
+def aggregate_rows_sparse(buffer: jax.Array, row_idx, weights,
+                          interpret: Optional[bool] = None) -> jax.Array:
+    """Kernel aggregation of ONLY the referenced rows (device gather of
+    the K rows, then ``staleness_agg``): the route for a buffer grown far
+    past its live set, where the full sweep of ``aggregate_rows`` would
+    read mostly dead rows. Never touches freed rows, so it needs no
+    finiteness guard."""
+    interpret = default_interpret() if interpret is None else interpret
+    idx, w = _pad_rows(row_idx, weights)
+    return _gather_agg(buffer, jnp.asarray(idx), jnp.asarray(w), interpret)
+
+
 def aggregate_rows_gather(buffer: jax.Array, row_idx, weights) -> jax.Array:
     """Exact-rows fallback: reduces ONLY the referenced rows (device
     gather + einsum, fused). Slower than the full-buffer sweep but immune
@@ -186,17 +213,16 @@ def _psum_agg(mesh):
     fn = _PSUM_AGG_CACHE.get(id(mesh))
     if fn is not None:
         return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def _local(buf, full_w):
         part = full_w.astype(jnp.float32) @ buf.astype(jnp.float32)
         return jax.lax.psum(part, "data")
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P("data", "model"), P("data")),
-        out_specs=P("model"), check_rep=False)
+        out_specs=P("model"), check_vma=False)
 
     @jax.jit
     def fn(buffer, idx, w):
@@ -228,33 +254,22 @@ def aggregate_rows_traced(buffer: jax.Array, row_idx: jax.Array,
     whose true branch is the identity — bitwise equal to the stepwise
     path whenever the data is finite, and the same exact-rows recompute
     when it is not. Runs the same inner jitted kernels (jit-in-jit
-    inlines); single-device branches see identically padded operands."""
+    inlines); every branch sees the operands padded as the eager path
+    pads them."""
     idx = jnp.asarray(row_idx, jnp.int32)
     w = jnp.asarray(weights, jnp.float32)
-    # the mesh route mirrors weighted_aggregate_rows: with a mesh the psum
-    # path is unconditional (the sparse heuristic and pallas/xla dispatch
-    # only arbitrate single-device execution). It takes the UNPADDED
-    # (idx, w): the scatter-add needs no sublane shape, the megastep
-    # regime is statically shaped anyway — and, decisively, the
-    # concatenate-of-repeated-slice pad pattern below is miscompiled by
-    # the 0.4.x SPMD partitioner whenever a shard_map coexists in the
-    # program: the partitioner books the padded vector as a partial sum
-    # over the "model" axis and inserts a spurious all-reduce that
-    # scales idx and w by the model-axis size (tests/test_mesh_plane.py
-    # guards the end-to-end fused/stepwise contract this broke).
-    if mesh is not None:
-        flat = _psum_agg(mesh)(buffer, idx, w)
-        return jax.lax.cond(
-            jnp.all(jnp.isfinite(flat)),
-            lambda f, b, i, ww: f,
-            lambda f, b, i, ww: _gather_weighted_sum(b, i, ww),
-            flat, buffer, idx, w)
     pad_k = (-idx.shape[0]) % SUBLANE
     if pad_k:       # zero-weight repeats of row 0, as _pad_rows does
         idx = jnp.concatenate([idx, jnp.repeat(idx[:1], pad_k)])
         w = jnp.concatenate([w, jnp.zeros((pad_k,), jnp.float32)])
-    if sparse:
-        return _gather_weighted_sum(buffer, idx, w)
+    # the mesh route mirrors weighted_aggregate_rows: with a mesh the psum
+    # path is unconditional (the sparse heuristic and pallas/xla dispatch
+    # only arbitrate single-device execution)
+    if mesh is not None:
+        flat = _psum_agg(mesh)(buffer, idx, w)
+    elif sparse:
+        return (_gather_agg(buffer, idx, w, interpret) if use_pallas
+                else _gather_weighted_sum(buffer, idx, w))
     else:
         flat = (_scatter_w_agg(buffer, idx, w, interpret) if use_pallas
                 else _scatter_w_matvec(buffer, idx, w))

@@ -6,10 +6,13 @@ applied upstream as ``-inf`` scores — DESIGN.md §10). ``lax.top_k`` is the
 XLA fast path; this kernel is the TPU variant that keeps the whole sweep
 in one pass over VMEM-resident tiles:
 
-grid over ``[G, B]`` score blocks; each program runs k rounds of
-(max, first-argmax, mask-out) over its VMEM tile — k is tiny (a cohort,
-<= a few hundred) against B — and writes its local top-k (values + GLOBAL
-indices) to a ``[G, k]`` candidate table. The caller then reduces the
+grid over ``[G, B]`` score blocks, eight blocks (one sublane tile) per
+program; each program runs k rounds of row-wise (max, first-argmax,
+mask-out) over its ``[8, B]`` VMEM tile — k is tiny (a cohort, <= a few
+hundred) against B — and writes each block's local top-k (values + GLOBAL
+indices) to a ``[G, k]`` candidate table. G pads to a multiple of 8 with
+``-inf`` blocks, which the wrapper trims, so every block shape meets the
+TPU's (8, 128) tiling rule. The caller then reduces the
 ``G*k`` candidates with one small ``lax.top_k``. Ties break toward the
 lowest index at both levels (first-argmax in-block, block-major candidate
 order across blocks), matching ``lax.top_k``'s tie order, so the two paths
@@ -23,20 +26,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK_TOPK = 1024   # scores per grid program (lane-aligned: 8 x 128)
+BLOCK_TOPK = 1024   # scores per block (lane-aligned: 8 x 128)
+ROWS = 8            # blocks per grid program (the fp32 sublane count)
 
 
 def _topk_kernel(x_ref, vals_ref, idx_ref, *, k: int, block: int):
-    x = x_ref[...].astype(jnp.float32)                       # [1, B]
-    base = pl.program_id(0) * block
+    x = x_ref[...].astype(jnp.float32)                       # [8, B]
+    row = jax.lax.broadcasted_iota(jnp.int32, (ROWS, 1), 0)
+    base = (pl.program_id(0) * ROWS + row) * block           # [8, 1]
     col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)    # 2D iota (TPU)
-    kcol = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (ROWS, k), 1)
 
     def body(j, carry):
         xv, vals, idx = carry
-        m = jnp.max(xv)
+        m = jnp.max(xv, axis=1, keepdims=True)
         # first index attaining the max (ties -> lowest, lax.top_k order)
-        a = jnp.min(jnp.where(xv == m, col, block))
+        a = jnp.min(jnp.where(xv == m, col, block), axis=1, keepdims=True)
         vals = jnp.where(kcol == j, m, vals)
         idx = jnp.where(kcol == j, base + a, idx)
         xv = jnp.where(col == a, -jnp.inf, xv)               # extract
@@ -44,8 +49,8 @@ def _topk_kernel(x_ref, vals_ref, idx_ref, *, k: int, block: int):
 
     _, vals, idx = jax.lax.fori_loop(
         0, k, body,
-        (x, jnp.full((1, k), -jnp.inf, jnp.float32),
-         jnp.zeros((1, k), jnp.int32)))
+        (x, jnp.full((ROWS, k), -jnp.inf, jnp.float32),
+         jnp.zeros((ROWS, k), jnp.int32)))
     vals_ref[...] = vals
     idx_ref[...] = idx
 
@@ -58,17 +63,21 @@ def block_topk(scores: jax.Array, k: int, *, block: int = BLOCK_TOPK,
     M = scores.shape[0]
     assert M % block == 0 and k <= block, (M, block, k)
     G = M // block
+    Gp = pl.cdiv(G, ROWS) * ROWS
+    x = scores.reshape(G, block).astype(jnp.float32)
+    if Gp != G:
+        x = jnp.pad(x, ((0, Gp - G), (0, 0)), constant_values=-jnp.inf)
     vals, idx = pl.pallas_call(
         functools.partial(_topk_kernel, k=k, block=block),
-        grid=(G,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, k), lambda i: (i, 0)),
-                   pl.BlockSpec((1, k), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((G, k), jnp.float32),
-                   jax.ShapeDtypeStruct((G, k), jnp.int32)],
+        grid=(Gp // ROWS,),
+        in_specs=[pl.BlockSpec((ROWS, block), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((ROWS, k), lambda i: (i, 0)),
+                   pl.BlockSpec((ROWS, k), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((Gp, k), jnp.float32),
+                   jax.ShapeDtypeStruct((Gp, k), jnp.int32)],
         interpret=interpret,
-    )(scores.reshape(G, block).astype(jnp.float32))
-    return vals, idx
+    )(x)
+    return vals[:G], idx[:G]
 
 
 def chosen_mask(idx: jax.Array, valid: jax.Array, n: int) -> jax.Array:

@@ -10,12 +10,14 @@ aggregation is a weighted psum over ``pod`` — see DESIGN.md §4).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _debug_mesh_shape(n_devices: int) -> tuple[int, int]:
@@ -36,7 +38,8 @@ def _debug_mesh_shape(n_devices: int) -> tuple[int, int]:
 
 def make_debug_mesh(n_devices: int = 8):
     """Small mesh for unit tests (requires xla_force_host_platform_device_count)."""
-    return jax.make_mesh(_debug_mesh_shape(n_devices), ("data", "model"))
+    return jax.make_mesh(_debug_mesh_shape(n_devices), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # TPU v5e hardware constants (roofline denominators)

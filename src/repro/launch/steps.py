@@ -150,8 +150,6 @@ def build_cell(arch: str, shape: ShapeConfig, mesh: Mesh, *,
             # partial sums, then a bf16-wire psum over the data axis (half
             # the all-reduce bytes; precision equals the bf16 storage dtype
             # of the model anyway). Weights ride the same cohort sharding.
-            from jax.experimental.shard_map import shard_map
-
             w_shard = NamedSharding(mesh, P("data"))
             leaves, treedef = jax.tree.flatten(upd)
             leaf_specs = [s.spec for s in jax.tree.leaves(u_shard)]
@@ -171,11 +169,11 @@ def build_cell(arch: str, shape: ShapeConfig, mesh: Mesh, *,
                                                  "data").astype(x.dtype))
                     return tuple(outs)
 
-                outs = shard_map(
+                outs = jax.shard_map(
                     body, mesh=mesh,
                     in_specs=(P("data"),) + tuple(leaf_specs),
                     out_specs=tuple(out_specs),
-                    check_rep=False)(weights, *lv)
+                    check_vma=False)(weights, *lv)
                 return jax.tree.unflatten(treedef, outs)
         else:
             def fl_aggregate(updates, weights):

@@ -9,8 +9,9 @@ from the parameter logical axes.
 The Adam path can dispatch to the fused ``kernels/fused_adam`` Pallas
 kernel (one HBM pass over p/m/v/g instead of ~12 unfused accesses),
 mirroring the aggregation dispatch pattern: a one-time ref-equivalence
-self-check gates ``auto`` dispatch, any failure falls back to the XLA
-implementation, and ``REPRO_ADAM_PATH=fused|xla|auto`` forces a path.
+self-check runs before the kernel is first chosen and raises on a mismatch
+or a kernel error (no quiet switch to XLA), and
+``REPRO_ADAM_PATH=fused|xla|auto`` forces a path.
 ``auto`` only takes the kernel on a real TPU backend — off-TPU the Pallas
 interpreter inside the per-step training loop would be a slowdown, unlike
 the once-per-round aggregation kernel. Fused state is flat ([Np] m/v
@@ -21,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from typing import Any, Callable, NamedTuple, Optional
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -84,42 +85,40 @@ def adam(lr: float, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8) -> Op
 
 
 # ------------------------------------------------------- fused Adam kernel
-_FUSED_ADAM_OK: Optional[bool] = None   # one-time self-check result
+_FUSED_ADAM_CHECKED = False   # the self-check has passed
 
 
-def _fused_adam_validated() -> bool:
-    """Ref-equivalence self-check of the fused Pallas Adam step against the
-    XLA implementation on a deterministic input (mirrors the aggregation
-    kernel's gating). Any mismatch or kernel error disables ``auto``
-    dispatch for the process."""
-    global _FUSED_ADAM_OK
-    if _FUSED_ADAM_OK is None:
-        try:
-            import numpy as np
+def _check_fused_adam() -> None:
+    """One-time ref-equivalence check of the fused Pallas Adam step against
+    the XLA implementation on a deterministic input (mirrors the
+    aggregation kernel's check), run before the kernel is first chosen. A
+    mismatch raises; so does a kernel that fails to compile or run."""
+    global _FUSED_ADAM_CHECKED
+    if _FUSED_ADAM_CHECKED:
+        return
+    import numpy as np
 
-            from repro.kernels import ref
-            from repro.kernels.fused_adam import BLOCK, fused_adam
-            from repro.kernels.ops import default_interpret
+    from repro.kernels import ref
+    from repro.kernels.fused_adam import BLOCK, fused_adam
+    from repro.kernels.ops import default_interpret
 
-            rng = np.random.default_rng(0)
-            N, t, lr = BLOCK, 3, 1e-3
-            p, g = rng.normal(size=(2, N)).astype(np.float32)
-            m = rng.normal(size=N).astype(np.float32) * 0.1
-            v = np.abs(rng.normal(size=N)).astype(np.float32) * 0.01
-            got = fused_adam(
-                jnp.asarray(p), jnp.asarray(m), jnp.asarray(v),
-                jnp.asarray(g), jnp.int32(t), lr=lr,
-                interpret=default_interpret())
-            want = ref.fused_adam(jnp.asarray(p), jnp.asarray(m),
-                                  jnp.asarray(v), jnp.asarray(g),
-                                  lr=lr, t=t)
-            _FUSED_ADAM_OK = all(
-                np.allclose(np.asarray(a), np.asarray(b),
-                            rtol=1e-5, atol=1e-6)
-                for a, b in zip(got, want))
-        except Exception:  # noqa: BLE001 — any kernel failure disables path
-            _FUSED_ADAM_OK = False
-    return _FUSED_ADAM_OK
+    rng = np.random.default_rng(0)
+    N, t, lr = BLOCK, 3, 1e-3
+    p, g = rng.normal(size=(2, N)).astype(np.float32)
+    m = rng.normal(size=N).astype(np.float32) * 0.1
+    v = np.abs(rng.normal(size=N)).astype(np.float32) * 0.01
+    got = fused_adam(jnp.asarray(p), jnp.asarray(m), jnp.asarray(v),
+                     jnp.asarray(g), jnp.int32(t), lr=lr,
+                     interpret=default_interpret())
+    want = ref.fused_adam(jnp.asarray(p), jnp.asarray(m), jnp.asarray(v),
+                          jnp.asarray(g), lr=lr, t=t)
+    for name, a, b in zip("pmv", got, want):
+        if not np.allclose(np.asarray(a), np.asarray(b),
+                           rtol=1e-5, atol=1e-6):
+            raise RuntimeError(
+                "fused_adam kernel disagrees with the reference on "
+                f"{jax.default_backend()} (output {name!r})")
+    _FUSED_ADAM_CHECKED = True
 
 
 def adam_fused(lr: float, b1: float = 0.9, b2: float = 0.999,
@@ -214,13 +213,13 @@ def build_optimizer(name: str, lr: float) -> Optimizer:
         path = os.environ.get("REPRO_ADAM_PATH", "auto")
         if path not in ("auto", "fused", "xla"):
             raise ValueError(f"unknown adam path {path!r}")
-        if path == "fused":
-            return adam_fused(lr)   # forced: kernel errors propagate
         if path == "auto":
             from repro.kernels.ops import on_tpu
-            if on_tpu() and _fused_adam_validated():
-                return adam_fused(lr)
-        return adam(lr)
+            path = "fused" if on_tpu() else "xla"
+        if path == "xla":
+            return adam(lr)
+        _check_fused_adam()
+        return adam_fused(lr)
     if name == "adafactor":
         return adafactor(lr)
     raise ValueError(f"unknown optimizer {name}")

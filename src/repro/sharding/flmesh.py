@@ -36,7 +36,7 @@ import os
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 #: the update-row buffer layout: [capacity over "data", W over "model"]
 ROW_SPEC = P("data", "model")
@@ -86,7 +86,8 @@ def build_fl_mesh(spec: str) -> Optional[Mesh]:
             f"mesh {spec!r} needs {n} devices but only "
             f"{jax.device_count()} are visible (on CPU, set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n})")
-    return jax.make_mesh((d, m), ("data", "model"))
+    return jax.make_mesh((d, m), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_axes(mesh: Optional[Mesh]) -> tuple[int, int]:

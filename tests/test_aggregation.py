@@ -150,3 +150,34 @@ def test_env_var_forces_xla(monkeypatch):
     ups = _ragged_trees(2, seed=5)
     weighted_aggregate(ups, np.array([0.4, 0.6], np.float32))
     assert aggregation.last_path() == "xla"
+
+
+def test_pallas_self_check_mismatch_raises(monkeypatch):
+    """A kernel that disagrees with the float64 reference fails the run;
+    it does not quietly move aggregation to XLA."""
+    from repro.core import aggregation
+    monkeypatch.setattr(aggregation, "_PALLAS_CHECKED", False)
+    monkeypatch.setattr(
+        aggregation.kernel_ops, "aggregate_pytree",
+        lambda ups, w, restore_dtype: jax.tree.map(lambda x: x + 1, ups[0]))
+    with pytest.raises(RuntimeError, match="disagrees"):
+        weighted_aggregate(_trees(2), np.array([0.5, 0.5], np.float32))
+
+
+def test_pallas_kernel_error_propagates(monkeypatch):
+    from repro.core import aggregation
+    from repro.core.aggregation import weighted_aggregate_rows
+    from repro.core.update_store import UpdateStore
+    from repro.kernels.ops import RavelSpec
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("kernel failed")
+
+    ups = _trees(2)
+    spec = RavelSpec(ups[0])
+    store = UpdateStore(spec.n_params, capacity=8)
+    ids = store.put(jnp.stack([spec.ravel(u) for u in ups]))
+    monkeypatch.setattr(aggregation.kernel_ops, "aggregate_rows", boom)
+    with pytest.raises(RuntimeError, match="kernel failed"):
+        weighted_aggregate_rows(store.buffer, ids,
+                                np.array([0.5, 0.5], np.float32), spec)

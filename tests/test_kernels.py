@@ -12,7 +12,7 @@ K0 = jax.random.PRNGKey(0)
 
 # -- staleness_agg -------------------------------------------------------------
 
-@pytest.mark.parametrize("k", [1, 3, 16])
+@pytest.mark.parametrize("k", [1, 3, 16, 2056])   # 2056: ragged row blocks
 @pytest.mark.parametrize("n", [1024, 4096])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_staleness_agg_sweep(k, n, dtype):

@@ -96,6 +96,23 @@ def test_eligibility_gates(kw, engages, data, model):
     assert (m_fused["megastep_rounds"] > 0) == engages
 
 
+def test_cohort_compile_failure_raises(data, model, monkeypatch):
+    """A cohort program that fails to build stops the run; it does not
+    quietly continue stepwise."""
+    eng = Scheduler(FLConfig(**megastep_cfg(rounds=3)), model, data,
+                    det_fleet(N_CLIENTS))
+    eng.run()                                # bootstrap rounds only
+    eng.cfg.rounds = 5
+    assert _plan(eng)[1] == "eligible"
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("cohort program failed to compile")
+
+    monkeypatch.setattr(eng.trainer, "cohort_fn_indexed", boom)
+    with pytest.raises(RuntimeError, match="failed to compile"):
+        _plan(eng)
+
+
 # ------------------------------------------------------ fallback boundaries
 def test_fallback_timer_armed_then_cleared(data, model):
     """An armed timer (the hedge barrier) must keep the fused path out —

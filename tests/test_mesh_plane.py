@@ -9,9 +9,9 @@ Contract under test:
 * Real meshes (``"<data>x<model>"``) keep selections/timing identical
   and params allclose (the psum and batch split reassociate float
   reductions), and the fused megastep stays BIT-identical to the
-  stepwise oracle *at the same mesh* — the regression guard for the
-  SPMD-partitioner hazards documented in kernels/ops.py and
-  core/megastep.py.
+  stepwise oracle *at the same mesh* — the regression guard for traced
+  code whose values could change when the SPMD partitioner shards it
+  (DESIGN.md §15).
 
 The test process itself keeps one CPU device; everything that needs a
 real multi-device mesh runs in a subprocess under
@@ -239,7 +239,7 @@ out["fused_bitwise_err"] = float(np.max(np.abs(p_f - p_s)))
 out["fused_rounds"] = int(m_f.get("megastep_rounds", 0))
 out["fallback"] = m_f.get("megastep_fallback_reason")
 
-# K=3 exercises the Kp>K cohort pad (the constant-map gather path)
+# K=3 exercises the Kp>K cohort pad and the sublane pad of the psum
 tr_s3, p_s3, _ = run("2x4", "stepwise", K=3)
 tr_f3, p_f3, m_f3 = run("2x4", "fused", K=3)
 out["fused_k3_trace_eq"] = (tr_f3 == tr_s3)
@@ -270,10 +270,9 @@ def test_sharded_plane_on_8_devices(tmp_path):
     assert rec["trace_2x4_eq_1x1"]
     assert rec["params_2x4_vs_1x1_err"] <= 1e-4
     # fused megastep at the SAME mesh is BIT-identical to stepwise —
-    # the guard for the SPMD-partitioner hazards (kernels/ops.py,
-    # core/megastep.py): in-trace threefry splits consumed by a sharded
-    # shard_map operand and concatenate-of-repeated-slice pads both
-    # silently corrupt values when miscompiled.
+    # the guard for in-trace code (threefry splits feeding the sharded
+    # shard_map, the cohort and sublane pads) whose values would change
+    # if the partitioner sharded it wrongly.
     assert rec["fused_trace_eq"] and rec["fused_bitwise_err"] == 0.0
     assert rec["fused_rounds"] >= 1, rec["fallback"]
     assert rec["fused_k3_trace_eq"] and rec["fused_k3_bitwise_err"] == 0.0

@@ -71,8 +71,24 @@ def test_adam_matches_reference_formula():
 
 # ------------------------------------------------------- fused Adam kernel
 def test_fused_adam_self_check_passes():
-    from repro.optim.optimizers import _fused_adam_validated
-    assert _fused_adam_validated()
+    from repro.optim import optimizers
+    optimizers._check_fused_adam()
+    assert optimizers._FUSED_ADAM_CHECKED
+
+
+def test_fused_adam_self_check_mismatch_raises(monkeypatch):
+    """A fused-Adam kernel that disagrees with the reference fails the
+    run; Adam does not quietly become the XLA version."""
+    from repro.kernels import fused_adam as fa
+    from repro.optim import optimizers
+
+    def wrong(p, m, v, g, t, **kw):
+        return p + 1.0, m, v
+
+    monkeypatch.setattr(optimizers, "_FUSED_ADAM_CHECKED", False)
+    monkeypatch.setattr(fa, "fused_adam", wrong)
+    with pytest.raises(RuntimeError, match="disagrees"):
+        optimizers._check_fused_adam()
 
 
 def test_fused_adam_matches_xla_adam_over_steps():

@@ -21,7 +21,8 @@ from repro.sharding.rules import (
 @pytest.fixture(scope="module")
 def mesh2d():
     # 1-device test process: trivial mesh still exercises the rule engine
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 class _FakeMesh:
@@ -122,7 +123,8 @@ import jax
 from repro.configs.base import SHAPES, ShapeConfig, get_config
 from repro.launch.steps import build_cell
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 shape = ShapeConfig("mini_train", 64, 8, "train")
 cell = build_cell("qwen3-1.7b", shape, mesh,
                   overrides=dict(n_layers=2, d_model=64, n_heads=4,

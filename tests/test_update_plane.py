@@ -195,7 +195,9 @@ def test_sparse_reference_set_uses_gather_and_stays_exact():
     store = UpdateStore(spec.n_params, capacity=64)  # >= 4 * max(K, 8)
     ids = store.put(jnp.stack([spec.ravel(u) for u in ups]))
     w = np.array([0.2, 0.5, 0.3], np.float32)
+    from repro.core import aggregation
     got = weighted_aggregate_rows(store.buffer, ids, w, spec)
+    assert aggregation.last_path() == "pallas"   # the kernel, on K rows
     want = weighted_aggregate(ups, w)
     for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
