@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.staleness import STALENESS_FNS
 from repro.kernels import ops as kernel_ops
 from repro.kernels.staleness_agg import BLOCK_N
@@ -195,7 +196,7 @@ def weighted_aggregate_rows(buffer, row_idx, weights,
     if mesh is not None:
         flat = kernel_ops.aggregate_rows_psum(buffer, row_idx, weights, mesh)
         _LAST_PATH = "psum"
-        if not bool(jnp.all(jnp.isfinite(flat))):
+        if not _all_finite(flat):
             flat = kernel_ops.aggregate_rows_gather(buffer, row_idx, weights)
         out = spec.unravel(flat[:spec.n_params], restore_dtype=False)
         if out_dtype is not None:
@@ -223,13 +224,20 @@ def weighted_aggregate_rows(buffer, row_idx, weights,
     # A non-finite result triggers one exact recompute over just the
     # referenced rows, so a diverged-then-pruned client can never poison a
     # later aggregate. The check reads the [W] result, not the buffer.
-    if not sparse and not bool(jnp.all(jnp.isfinite(flat))):
+    if not sparse and not _all_finite(flat):
         flat = kernel_ops.aggregate_rows_gather(buffer, row_idx, weights)
     # buffer rows are block-padded (W >= N); unravel exactly once per round
     out = spec.unravel(flat[:spec.n_params], restore_dtype=False)
     if out_dtype is not None:
         out = jax.tree.map(lambda x: x.astype(out_dtype), out)
     return out
+
+
+def _all_finite(flat: jax.Array) -> bool:
+    """The finiteness guard's blocking read of one device scalar."""
+    finite = jnp.all(jnp.isfinite(flat))
+    with obs.sync("agg_finite"):
+        return bool(finite)
 
 
 def rows_dispatch(buffer_rows: int, k: int, n_params: int,

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.update_store import scatter_rows
 from repro.optim import apply_updates, build_optimizer
 from repro.sharding import flmesh
@@ -135,8 +136,9 @@ class CohortTrainer:
             def body(carry, s):
                 params, opt_state, key = carry
                 key, k = jax.random.split(key)
-                idx = jax.random.randint(k, (B,), 0, jnp.maximum(n_i, 1))
-                bx, by = fetch(idx)
+                with jax.named_scope("cohort.gather"):
+                    idx = jax.random.randint(k, (B,), 0, jnp.maximum(n_i, 1))
+                    bx, by = fetch(idx)
                 batch = {"x": bx, "y": by}
 
                 def loss_fn(p):
@@ -145,16 +147,22 @@ class CohortTrainer:
                         l = l + 0.5 * mu * _l2_sq(p, params0)
                     return l
 
-                loss, grads = jax.value_and_grad(loss_fn)(params)
+                with jax.named_scope("cohort.fwd_bwd"):
+                    loss, grads = jax.value_and_grad(loss_fn)(params)
                 if use_cv:
                     grads = jax.tree.map(lambda g, a, b: g - a + b, grads, ci, cg)
-                upd, new_opt = opt.update(grads, opt_state, params)
-                newp = apply_updates(params, upd)
-                active = s < steps
-                sel = lambda a, b: jnp.where(active, a, b)
-                params = jax.tree.map(sel, newp, params)
-                opt_state = jax.tree.map(sel, new_opt, opt_state)
-                return (params, opt_state, key), jnp.where(active, loss, 0.0)
+                with jax.named_scope("cohort.optimizer"):
+                    upd, new_opt = opt.update(grads, opt_state, params)
+                    newp = apply_updates(params, upd)
+                # a lane past its own step count keeps its state: the
+                # step ran and is thrown away (``train.useful_steps``)
+                with jax.named_scope("cohort.mask"):
+                    active = s < steps
+                    sel = lambda a, b: jnp.where(active, a, b)
+                    params = jax.tree.map(sel, newp, params)
+                    opt_state = jax.tree.map(sel, new_opt, opt_state)
+                    loss = jnp.where(active, loss, 0.0)
+                return (params, opt_state, key), loss
 
             (params, _, _), losses = jax.lax.scan(
                 body, (params0, opt_state, key), jnp.arange(max_steps))
@@ -239,8 +247,9 @@ class CohortTrainer:
         def cohort_flat(*args):
             lead, (buffer, row_ids) = args[:n_lead], args[n_lead:]
             out_params, ci_new, losses = v(*lead)
-            buffer = scatter_rows(buffer, row_ids,
-                                  jax.tree.leaves(out_params))
+            with jax.named_scope("cohort.scatter_rows"):
+                buffer = scatter_rows(buffer, row_ids,
+                                      jax.tree.leaves(out_params))
             return buffer, ci_new, losses
 
         return jax.jit(cohort_flat, donate_argnums=(n_lead,))
@@ -282,7 +291,7 @@ class CohortTrainer:
                      n_i: np.ndarray, steps: np.ndarray,
                      c_global: Optional[Pytree] = None,
                      c_clients: Optional[Pytree] = None, *,
-                     update_sink=None):
+                     update_sink=None, round_: int = -1):
         """Host data plane: X [K, N_max, ...], y [K, N_max], n_i/steps [K]
         are uploaded per dispatch (counted in ``data_h2d_bytes``).
         Returns (params [K, ...] stacked, c_clients', mean losses [K]).
@@ -291,7 +300,8 @@ class CohortTrainer:
         instead stay on device: the jitted cohort fn flattens them to
         [K, W] fp32 rows (RavelSpec leaf order) and scatters them into the
         sink's donated buffer in the same program; the first return value
-        is then the [K] allocated row ids."""
+        is then the [K] allocated row ids. ``round_`` only labels the
+        dispatch's spans."""
         flat_updates = update_sink is not None
         K = X.shape[0]
         # pad the cohort to a power-of-two bucket: one compile serves every
@@ -304,28 +314,28 @@ class CohortTrainer:
             n_i = padt(np.asarray(n_i))
             steps = np.concatenate([steps, np.zeros(Kp - K, steps.dtype)])
         max_steps = _steps_bucket(int(steps.max()))
-        cache_key = self._config_key() + (Kp, max_steps, X.shape[1:],
-                                          y.dtype, flat_updates, "host")
-        fn = self._compiled(cache_key, max_steps, flat_updates, indexed=False)
-        keys = self._cohort_keys(Kp)
-        c_global, c_clients = self._pad_variates(global_params, c_global,
-                                                 c_clients, Kp, K)
-        X, y = np.asarray(X), np.asarray(y)
-        self.data_h2d_bytes += X.nbytes + y.nbytes
-        trim = lambda t: jax.tree.map(lambda a: a[:K], t)
-        lead = (global_params, jnp.asarray(X), jnp.asarray(y),
-                jnp.asarray(n_i), jnp.asarray(steps), keys, c_global,
-                c_clients)
-        if flat_updates:
-            return self._run_flat(fn, lead, update_sink, Kp, K, trim)
-        out_params, ci_new, losses = fn(*lead)
-        return trim(out_params), trim(ci_new), np.asarray(losses)[:K]
+        with obs.span("fl.train", round=round_, lanes=Kp, clients=K,
+                      steps=max_steps):
+            cache_key = self._config_key() + (Kp, max_steps, X.shape[1:],
+                                              y.dtype, flat_updates, "host")
+            fn = self._compiled(cache_key, max_steps, flat_updates,
+                                indexed=False)
+            keys = self._cohort_keys(Kp)
+            c_global, c_clients = self._pad_variates(global_params, c_global,
+                                                     c_clients, Kp, K)
+            X, y = np.asarray(X), np.asarray(y)
+            self.data_h2d_bytes += X.nbytes + y.nbytes
+            lead = (global_params, jnp.asarray(X), jnp.asarray(y),
+                    jnp.asarray(n_i), jnp.asarray(steps), keys, c_global,
+                    c_clients)
+            return self._run(fn, lead, update_sink, K, Kp, max_steps,
+                             steps, round_)
 
     def train_cohort_indexed(self, global_params: Pytree, store,
                              selection, n_i: np.ndarray, steps: np.ndarray,
                              c_global: Optional[Pytree] = None,
                              c_clients: Optional[Pytree] = None, *,
-                             update_sink=None):
+                             update_sink=None, round_: int = -1):
         """Device data plane: the cohort is a ``[K]`` vector of client
         indices into ``store`` (a ``DatasetStore``); every minibatch is
         gathered out of the resident buffers inside the jit — zero H2D
@@ -343,21 +353,22 @@ class CohortTrainer:
             n_i = np.concatenate([n_i, np.repeat(n_i[-1:], Kp - K)])
             steps = np.concatenate([steps, np.zeros(Kp - K, steps.dtype)])
         max_steps = _steps_bucket(int(steps.max()))
-        cache_key = self._config_key() + (Kp, max_steps, store.X.shape[1:],
-                                          store.y.dtype, flat_updates,
-                                          "device")
-        fn = self._compiled(cache_key, max_steps, flat_updates, indexed=True)
-        keys = self._cohort_keys(Kp)
-        c_global, c_clients = self._pad_variates(global_params, c_global,
-                                                 c_clients, Kp, K)
-        trim = lambda t: jax.tree.map(lambda a: a[:K], t)
-        lead = (global_params, jnp.asarray(sel), jnp.asarray(n_i),
-                jnp.asarray(steps), keys, c_global, c_clients,
-                store.X, store.y)
-        if flat_updates:
-            return self._run_flat(fn, lead, update_sink, Kp, K, trim)
-        out_params, ci_new, losses = fn(*lead)
-        return trim(out_params), trim(ci_new), np.asarray(losses)[:K]
+        with obs.span("fl.train", round=round_, lanes=Kp, clients=K,
+                      steps=max_steps):
+            cache_key = self._config_key() + (Kp, max_steps,
+                                              store.X.shape[1:],
+                                              store.y.dtype, flat_updates,
+                                              "device")
+            fn = self._compiled(cache_key, max_steps, flat_updates,
+                                indexed=True)
+            keys = self._cohort_keys(Kp)
+            c_global, c_clients = self._pad_variates(global_params, c_global,
+                                                     c_clients, Kp, K)
+            lead = (global_params, jnp.asarray(sel), jnp.asarray(n_i),
+                    jnp.asarray(steps), keys, c_global, c_clients,
+                    store.X, store.y)
+            return self._run(fn, lead, update_sink, K, Kp, max_steps,
+                             steps, round_)
 
     def cohort_fn_indexed(self, store, K: int, max_steps_raw: int):
         """The compiled indexed-flat cohort fn for a fixed (K, step-budget)
@@ -374,13 +385,41 @@ class CohortTrainer:
                             indexed=True)
         return fn, Kp, max_steps
 
-    def _run_flat(self, fn, lead, update_sink, Kp, K, trim):
-        # padded cohort entries run 0 active steps, so their rows hold
-        # the unchanged global model — written then recycled right away
-        ids = update_sink.alloc(Kp)
-        new_buffer, ci_new, losses = fn(*lead, update_sink.buffer,
-                                        jnp.asarray(ids))
-        update_sink.buffer = new_buffer
-        if Kp != K:
-            update_sink.free(ids[K:])
-        return ids[:K], trim(ci_new), np.asarray(losses)[:K]
+    def count_lane_steps(self, params: Pytree, Kp: int, K: int,
+                         max_steps: int, useful: int, rounds: int = 1) -> None:
+        """Count ``rounds`` dispatches of ``K`` clients on ``Kp`` lanes of
+        ``max_steps`` steps, ``useful`` of whose steps belong to a real
+        client's own step count: ``train.lane_steps`` (every lane-step the
+        program runs), ``train.real_lane_steps`` (those of real clients),
+        ``train.useful_steps``, and, where the optimizer's kernel knows
+        its traffic (``Optimizer.step_bytes``: the fused Adam kernel),
+        ``train.adam_bytes``, the HBM bytes of its updates."""
+        lane_steps = rounds * Kp * max_steps
+        obs.count("train.lane_steps", lane_steps)
+        obs.count("train.real_lane_steps", rounds * K * max_steps)
+        obs.count("train.useful_steps", int(useful))
+        if self.opt.step_bytes is not None:
+            n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
+            obs.count("train.adam_bytes", lane_steps * self.opt.step_bytes(n))
+
+    def _run(self, fn, lead, update_sink, K, Kp, max_steps, steps, round_):
+        """Launch a padded cohort; the losses are its one blocking read."""
+        self.count_lane_steps(lead[0], Kp, K, max_steps, int(steps.sum()))
+        trim = lambda t: jax.tree.map(lambda a: a[:K], t)
+        if update_sink is None:
+            out_params, ci_new, losses = fn(*lead)
+            out = trim(out_params)
+        else:
+            # padded cohort entries run 0 active steps, so their rows hold
+            # the unchanged global model — written then recycled right away
+            ids = update_sink.alloc(Kp)
+            new_buffer, ci_new, losses = fn(*lead, update_sink.buffer,
+                                            jnp.asarray(ids))
+            update_sink.buffer = new_buffer
+            if Kp != K:
+                update_sink.free(ids[K:])
+            out = ids[:K]
+        ci_new = trim(ci_new)
+        with obs.sync("cohort_losses", round=round_):
+            losses = np.asarray(losses)[:K]
+        return out, ci_new, losses

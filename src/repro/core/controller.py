@@ -36,6 +36,7 @@ from __future__ import annotations
 
 from typing import Callable, Optional
 
+from repro import obs
 # Re-exported for backwards compatibility: these lived here before the
 # scheduler redesign split the services out (PR 3).
 from repro.core.services import (FLConfig, FLRuntime, RoundLog,  # noqa: F401
@@ -68,7 +69,8 @@ class Controller(FLRuntime):
                     # the poll loop has no RoundStarted event; the marker
                     # gives its journal the same open boundary
                     self.durability.record_marker("round_open", round_)
-            selection = strat.select(self.db, round_)
+            with obs.span("fl.select", round=round_):
+                selection = strat.select(self.db, round_)
             if not selection:
                 # every client busy: advance until something completes —
                 # or, when the fleet is empty under open-loop traffic,

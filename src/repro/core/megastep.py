@@ -61,6 +61,7 @@ from typing import Any, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.fleet_store import IDLE
 
 Pytree = Any
@@ -423,9 +424,13 @@ def run_megastep(sched, plan: MegastepPlan) -> None:
         jnp.asarray(plan.d32_col), jnp.asarray(plan.rank_col),
         jnp.float32(plan.beta32), jnp.float32(plan.dec32))
     params_f, buffer_f, _, _, _, booster_f, key_f = carry
-    sel_np = np.asarray(ys[0])          # [R, K] selected slots
-    ids_np = np.asarray(ys[1])          # [R, Kp] update rows
-    losses_np = np.asarray(ys[2])       # [R, K]
+    with obs.sync("megastep", round=db.round, rounds=R):
+        sel_np = np.asarray(ys[0])      # [R, K] selected slots
+        ids_np = np.asarray(ys[1])      # [R, Kp] update rows
+        losses_np = np.asarray(ys[2])   # [R, K]
+    sched.trainer.count_lane_steps(sched.params, Kp, K, plan.max_steps,
+                                   int(plan.steps_col[sel_np].sum()),
+                                   rounds=R)
 
     # ---- host replay: the real code paths, no device work ----------------
     from repro.core.services import RoundLog, _Payload
@@ -497,5 +502,7 @@ def try_megastep(sched) -> bool:
     sched.megastep_fallback_reason = reason
     if plan is None:
         return False
-    run_megastep(sched, plan)
+    with obs.span("fl.megastep", round=sched.db.round, rounds=plan.R,
+                  lanes=plan.Kp, clients=plan.K, steps=plan.max_steps):
+        run_megastep(sched, plan)
     return True

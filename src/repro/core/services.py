@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.aggregation import weighted_aggregate, weighted_aggregate_rows
 from repro.core.client import CohortTrainer
 from repro.core.data_plane import DatasetStore, dataset_store, resolve_data_plane
@@ -700,18 +701,19 @@ class FLRuntime:
             # clamp silently) can never see one
             out, ci_new, losses = self.trainer.train_cohort_indexed(
                 self.params, self.dataset, selection, n_i, steps, cg, ci,
-                update_sink=sink)
+                update_sink=sink, round_=round_)
         else:
             out, ci_new, losses = self.trainer.train_cohort(
                 self.params, self.data.X[selection], self.data.y[selection],
-                n_i, steps, cg, ci, update_sink=sink)
+                n_i, steps, cg, ci, update_sink=sink, round_=round_)
         if device:
             # trained models never left the device: the jitted cohort fn
             # scattered them into the store's persistent row buffer; only
             # the [K] row handles come back
             row_ids = out
         else:
-            out = jax.tree.map(np.asarray, out)  # host copies
+            with obs.sync("blob_copy", round=round_):
+                out = jax.tree.map(np.asarray, out)  # host copies
             self.update_host_bytes += sum(
                 l.nbytes for l in jax.tree.leaves(out))
         if self.strategy.needs_scaffold:
@@ -885,56 +887,61 @@ class FLRuntime:
 
     # ------------------------------------------------- aggregation service
     def aggregate_round(self, round_: int) -> tuple[int, int, float]:
-        strat = self.strategy
-        pending = [r for r in self.db.pending_results(self.cfg.max_staleness, round_)
-                   if strat.usable(r, round_)]
-        if not pending:
-            return 0, 0, float("nan")
-        weights = np.array([strat.result_weight(r, round_) for r in pending],
-                           np.float64)
-        total = weights.sum()
-        if not np.isfinite(total) or total <= 0:
-            # e.g. Eq. 1 zeroes round-0 updates at T=1: fall back to
-            # cardinality weighting so the aggregation stays well-defined
-            weights = np.array([r.n_samples for r in pending], np.float64)
-            total = weights.sum() or 1.0
-        # cast THEN normalize in f32: when the weights are integer-valued
-        # (the all-current-round case — eq2(T,T)=1 exactly, so the weight
-        # is n_samples) both operands are exactly representable and the
-        # quotient is a single correctly-rounded f32 division, making the
-        # result independent of host-vs-device summation order — the
-        # anchor that lets the fused megastep's in-scan normalization be
-        # bitwise identical to this line
-        weights = weights.astype(np.float32)
-        weights = weights / weights.sum()
-        out_dtype = jax.tree.leaves(self.params)[0].dtype
-        if self.update_plane == "device":
-            # row-index fast path: gather rows out of the persistent device
-            # buffer, one kernel dispatch, one unravel — no host traffic
-            rows = [r.update_row for r in pending]
-            assert all(r >= 0 for r in rows), \
-                "pending result without a row handle on the device plane"
-            self.params = weighted_aggregate_rows(
-                self.store.buffer, rows, weights, self.spec,
-                out_dtype=out_dtype, mesh=self.mesh)
-            self.store.free(rows)
-        else:
-            updates = [jax.tree.map(jnp.asarray, self.db.blobs[r.update_key])
-                       for r in pending]
-            self.update_host_bytes += sum(
-                l.nbytes for u in updates for l in jax.tree.leaves(u))
-            self.params = weighted_aggregate(updates, weights,
-                                             out_dtype=out_dtype)
-        n_stale = sum(1 for r in pending if r.round < round_)
-        mean_dur = float(np.mean([r.train_duration for r in pending]))
-        self.db.mark_aggregated(pending)
-        # prune: results too stale to ever be usable again
-        drop = [r for r in self.db.results
-                if not r.aggregated and round_ - r.round >= self.cfg.max_staleness]
-        if self.update_plane == "device":
-            self.store.free([r.update_row for r in drop if r.update_row >= 0])
-        self.db.mark_aggregated(drop)
-        return len(pending), n_stale, mean_dur
+        with obs.span("fl.aggregate", round=round_):
+            strat = self.strategy
+            pending = [r for r in self.db.pending_results(
+                           self.cfg.max_staleness, round_)
+                       if strat.usable(r, round_)]
+            if not pending:
+                return 0, 0, float("nan")
+            weights = np.array([strat.result_weight(r, round_)
+                                for r in pending], np.float64)
+            total = weights.sum()
+            if not np.isfinite(total) or total <= 0:
+                # e.g. Eq. 1 zeroes round-0 updates at T=1: fall back to
+                # cardinality weighting so the aggregation stays well-defined
+                weights = np.array([r.n_samples for r in pending], np.float64)
+                total = weights.sum() or 1.0
+            # cast THEN normalize in f32: when the weights are integer-valued
+            # (the all-current-round case — eq2(T,T)=1 exactly, so the weight
+            # is n_samples) both operands are exactly representable and the
+            # quotient is a single correctly-rounded f32 division, making the
+            # result independent of host-vs-device summation order — the
+            # anchor that lets the fused megastep's in-scan normalization be
+            # bitwise identical to this line
+            weights = weights.astype(np.float32)
+            weights = weights / weights.sum()
+            out_dtype = jax.tree.leaves(self.params)[0].dtype
+            if self.update_plane == "device":
+                # row-index fast path: gather rows out of the persistent device
+                # buffer, one kernel dispatch, one unravel — no host traffic
+                rows = [r.update_row for r in pending]
+                assert all(r >= 0 for r in rows), \
+                    "pending result without a row handle on the device plane"
+                self.params = weighted_aggregate_rows(
+                    self.store.buffer, rows, weights, self.spec,
+                    out_dtype=out_dtype, mesh=self.mesh)
+                self.store.free(rows)
+            else:
+                updates = [jax.tree.map(jnp.asarray,
+                                        self.db.blobs[r.update_key])
+                           for r in pending]
+                self.update_host_bytes += sum(
+                    l.nbytes for u in updates for l in jax.tree.leaves(u))
+                self.params = weighted_aggregate(updates, weights,
+                                                 out_dtype=out_dtype)
+            n_stale = sum(1 for r in pending if r.round < round_)
+            mean_dur = float(np.mean([r.train_duration for r in pending]))
+            self.db.mark_aggregated(pending)
+            # prune: results too stale to ever be usable again
+            drop = [r for r in self.db.results
+                    if not r.aggregated
+                    and round_ - r.round >= self.cfg.max_staleness]
+            if self.update_plane == "device":
+                self.store.free([r.update_row for r in drop
+                                 if r.update_row >= 0])
+            self.db.mark_aggregated(drop)
+            return len(pending), n_stale, mean_dur
 
     # -------------------------------------------------- evaluation service
     def _build_eval_scan(self):
@@ -968,23 +975,27 @@ class FLRuntime:
         return run, batches
 
     def evaluate(self) -> float:
-        if not hasattr(self.model, "predict"):
-            # models exposing only ``accuracy`` (e.g. LM adapters with
-            # internal target masking) keep the legacy per-batch loop;
-            # batches are weighted by size so both paths report the same
-            # statistic (exact sample mean) on ragged tails
-            xs, ys = self.data.eval_x, self.data.eval_y
-            total, bs = 0.0, 256
-            for i in range(0, len(xs), bs):
-                xb, yb = xs[i:i + bs], ys[i:i + bs]
-                total += float(self._eval_fn(
-                    self.params, {"x": jnp.asarray(xb),
-                                  "y": jnp.asarray(yb)})) * len(xb)
-            return total / max(len(xs), 1)
-        if self._eval_scan is None:
-            self._eval_scan = self._build_eval_scan()
-        run, batches = self._eval_scan
-        return float(run(self.params, *batches))
+        with obs.span("fl.eval", round=self.db.round):
+            if not hasattr(self.model, "predict"):
+                # models exposing only ``accuracy`` (e.g. LM adapters with
+                # internal target masking) keep the legacy per-batch loop;
+                # batches are weighted by size so both paths report the same
+                # statistic (exact sample mean) on ragged tails
+                xs, ys = self.data.eval_x, self.data.eval_y
+                total, bs = 0.0, 256
+                for i in range(0, len(xs), bs):
+                    xb, yb = xs[i:i + bs], ys[i:i + bs]
+                    acc = self._eval_fn(self.params, {"x": jnp.asarray(xb),
+                                                      "y": jnp.asarray(yb)})
+                    with obs.sync("eval", round=self.db.round):
+                        total += float(acc) * len(xb)
+                return total / max(len(xs), 1)
+            if self._eval_scan is None:
+                self._eval_scan = self._build_eval_scan()
+            run, batches = self._eval_scan
+            acc = run(self.params, *batches)
+            with obs.sync("eval", round=self.db.round):
+                return float(acc)
 
     # ---------------------------------------------------------------- metrics
     def metrics(self) -> dict:

@@ -34,6 +34,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.protocol import (Action, Aggregate, ClientJoined, ClientLeft,
                                  DatabaseView, EndRun, Event, Hedge, Invoke,
                                  InvocationFailed, LoopDrained, ReactivePolicy,
@@ -86,7 +87,8 @@ class LegacyStrategyAdapter(ReactivePolicy):
     def _open(self, view: DatabaseView) -> list[Action]:
         """Round start (or re-select once a client went idle)."""
         s = self.strategy
-        selection = s.select(view.db, view.round)
+        with obs.span("fl.select", round=view.round):
+            selection = s.select(view.db, view.round)
         if not selection:
             self._phase = "selecting"
             return []

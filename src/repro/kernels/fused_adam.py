@@ -19,6 +19,13 @@ from jax.experimental import pallas as pl
 BLOCK = 8 * 1024
 
 
+def hbm_bytes(n_params: int) -> int:
+    """HBM bytes one ``fused_adam`` call moves for ``n_params`` fp32
+    parameters: p, m, v and g read and p, m and v written once each, every
+    one padded to ``BLOCK`` (the two bias-correction scalars aside)."""
+    return 7 * 4 * (n_params + (-n_params) % BLOCK)
+
+
 def _adam_kernel(p_ref, m_ref, v_ref, g_ref, c_ref,
                  po_ref, mo_ref, vo_ref, *, lr, b1, b2, eps):
     g = g_ref[...].astype(jnp.float32)

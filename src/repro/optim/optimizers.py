@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +34,9 @@ class Optimizer(NamedTuple):
     init: Callable[[Pytree], Pytree]
     update: Callable[[Pytree, Pytree, Pytree], tuple[Pytree, Pytree]]
     name: str
+    #: HBM bytes of one update of ``n`` parameters, where a kernel whose
+    #: traffic is known runs it
+    step_bytes: Optional[Callable[[int], int]] = None
 
 
 def apply_updates(params: Pytree, updates: Pytree) -> Pytree:
@@ -127,7 +130,7 @@ def adam_fused(lr: float, b1: float = 0.9, b2: float = 0.999,
     grads are raveled through the shared ``RavelSpec`` contract into one
     flat fp32 vector padded to the kernel block (pad lanes carry zero
     grads -> exact no-ops); m/v state is kept flat."""
-    from repro.kernels.fused_adam import BLOCK, fused_adam
+    from repro.kernels.fused_adam import BLOCK, fused_adam, hbm_bytes
     from repro.kernels.ops import RavelSpec, default_interpret
 
     def _flat(spec, tree):
@@ -153,7 +156,7 @@ def adam_fused(lr: float, b1: float = 0.9, b2: float = 0.999,
         upd = spec.unravel(upd_flat[:spec.n_params], restore_dtype=False)
         return upd, {"m": mo, "v": vo, "t": t}
 
-    return Optimizer(init, update, "adam-fused")
+    return Optimizer(init, update, "adam-fused", hbm_bytes)
 
 
 def adafactor(lr: float = 1e-2, eps: float = 1e-30, clip: float = 1.0) -> Optimizer:
