@@ -3,10 +3,12 @@
 On real hardware every client is an independent FaaS function. In the
 simulator the *learning* is real but executed cohort-vectorized: the local
 SGD/Adam loop of every client invoked at the same simulated instant runs
-under one ``vmap`` (padded to the cohort's max step count, with per-client
-step masking). Simulated durations come from the hardware model, so the
-timing behaviour matches per-client execution while the host does one
-batched computation (a beyond-paper systems optimization, DESIGN.md §2).
+under one ``vmap``, in one loop that runs as many steps as the cohort's
+longest client (read on the device, so no step count is compiled in),
+with per-client step masking. Simulated durations come from the hardware
+model, so the timing behaviour matches per-client execution while the
+host does one batched computation (a beyond-paper systems optimization,
+DESIGN.md §2).
 
 Two data planes feed the cohort fn (DESIGN.md §2, ``core.data_plane``):
 
@@ -56,15 +58,9 @@ def _bucket(x: int, floor: int) -> int:
     return b
 
 
-def _steps_bucket(steps: int, floor: int = 8) -> int:
-    """Round max step counts to power-of-two buckets to bound recompiles."""
-    return _bucket(steps, floor)
-
-
-# Cohort sizes bucket separately from step counts: a K=1 reinforcement or
-# re-invocation used to pad to the step floor of 8 (~8x wasted lanes); the
-# cohort floor is 2, so solo dispatches run 2 padded lanes and mixed
-# selection sizes still compile O(log K) variants.
+# Cohort sizes bucket to powers of two: the cohort floor is 2, so solo
+# dispatches (a K=1 reinforcement or re-invocation) run 2 padded lanes and
+# mixed selection sizes compile O(log K) variants.
 DEFAULT_COHORT_FLOOR = 2
 
 
@@ -121,20 +117,21 @@ class CohortTrainer:
         self.data_h2d_bytes = 0   # training-input bytes uploaded (host plane)
 
     # ----------------------------------------------------------- single fn
-    def _make_fn(self, max_steps: int, flat_updates: bool = False,
-                 indexed: bool = False):
+    def _make_fn(self, flat_updates: bool = False, indexed: bool = False):
         model, opt = self.model, self.opt
         B, mu, use_cv, lr = self.batch_size, self.prox_mu, self.scaffold, self.lr
 
-        def local_train(params0, fetch, n_i, steps, key, cg, ci):
+        def local_train(params0, fetch, n_i, steps, key, cg, ci, trips):
             # ``fetch(idx) -> (x, y)`` abstracts the minibatch gather: the
             # host plane indexes this lane's [N_max, ...] slice, the device
             # plane gathers straight out of the resident [M, N_max, ...]
             # buffers — identical values, so the planes stay bit-identical.
+            # ``trips`` is the cohort's longest step count, one unbatched
+            # value, so the vmapped loop stays a single ``while``.
             opt_state = opt.init(params0)
 
-            def body(carry, s):
-                params, opt_state, key = carry
+            def body(s, carry):
+                params, opt_state, key, loss_sum = carry
                 key, k = jax.random.split(key)
                 with jax.named_scope("cohort.gather"):
                     idx = jax.random.randint(k, (B,), 0, jnp.maximum(n_i, 1))
@@ -161,12 +158,12 @@ class CohortTrainer:
                     sel = lambda a, b: jnp.where(active, a, b)
                     params = jax.tree.map(sel, newp, params)
                     opt_state = jax.tree.map(sel, new_opt, opt_state)
-                    loss = jnp.where(active, loss, 0.0)
-                return (params, opt_state, key), loss
+                    loss_sum = loss_sum + jnp.where(active, loss, 0.0)
+                return params, opt_state, key, loss_sum
 
-            (params, _, _), losses = jax.lax.scan(
-                body, (params0, opt_state, key), jnp.arange(max_steps))
-            mean_loss = jnp.sum(losses) / jnp.maximum(steps, 1)
+            params, _, _, loss_sum = jax.lax.fori_loop(
+                0, trips, body, (params0, opt_state, key, jnp.float32(0.0)))
+            mean_loss = loss_sum / jnp.maximum(steps, 1)
             if use_cv:
                 # c_i' = c_i - c + (w0 - w) / (K * lr)
                 denom = jnp.maximum(steps, 1).astype(jnp.float32) * lr
@@ -181,25 +178,29 @@ class CohortTrainer:
             # Device data plane: per-lane client index into the resident
             # dataset buffers (unbatched jit args — never re-uploaded, never
             # baked into the program as constants). The lane slices its
-            # client's rows ONCE before the scan — a device-device gather —
+            # client's rows ONCE before the loop — a device-device gather —
             # so the per-step minibatch gather sees the same lane-local
             # operand as the host path (a per-step two-level gather from
             # the full buffer lowers to a slow batched-gather on XLA CPU).
-            def client_fn(params0, cidx, n_i, steps, key, cg, ci, DX, Dy):
+            def client_fn(params0, cidx, n_i, steps, key, cg, ci, DX, Dy,
+                          trips):
                 Xl, yl = DX[cidx], Dy[cidx]
                 return local_train(
                     params0, lambda idx: (Xl[idx], yl[idx]),
-                    n_i, steps, key, cg, ci)
+                    n_i, steps, key, cg, ci, trips)
 
             v = jax.vmap(client_fn,
-                         in_axes=(None, 0, 0, 0, 0, None, 0, None, None))
+                         in_axes=(None, 0, 0, 0, 0, None, 0, None, None,
+                                  None))
             if self.mesh is not None:
                 # Shard the cohort batch over the "data" axis: each device
                 # vmaps its Kp/data lanes against the replicated dataset
                 # buffers, so per-lane train work and minibatch gathers are
                 # shard-local. Per-lane training is independent, so each
                 # lane's outputs are the same values the unsharded vmap
-                # produces — only aggregation reassociates floats.
+                # produces — only aggregation reassociates floats. The
+                # trip count is the whole cohort's, replicated, so every
+                # shard loops as far as the unsharded program.
                 #
                 # The [Kp, 2] lane-key table enters REPLICATED (P()) and
                 # each shard slices its own lane block below, so the key
@@ -213,29 +214,34 @@ class CohortTrainer:
                 vv = v
 
                 def _shard_body(params0, cidx, n_i, steps, keys, cg, ci,
-                                DX, Dy):
+                                DX, Dy, trips):
                     kp_l = cidx.shape[0]      # this shard's lane count
                     start = jax.lax.axis_index("data") * kp_l
                     keys_l = jax.lax.dynamic_slice_in_dim(keys, start, kp_l)
                     return vv(params0, cidx, n_i, steps, keys_l, cg, ci,
-                              DX, Dy)
+                              DX, Dy, trips)
 
                 v = jax.shard_map(
                     _shard_body, mesh=self.mesh,
                     in_specs=(P(), P("data"), P("data"), P("data"),
-                              P(), P(), P("data"), P(), P()),
+                              P(), P(), P("data"), P(), P(), P()),
                     out_specs=(P("data"), P("data"), P("data")),
                     check_vma=False)
-            n_lead = 9
+            n_lead, steps_at = 9, 3
         else:
-            def client_fn(params0, X, y, n_i, steps, key, cg, ci):
+            def client_fn(params0, X, y, n_i, steps, key, cg, ci, trips):
                 return local_train(params0, lambda idx: (X[idx], y[idx]),
-                                   n_i, steps, key, cg, ci)
+                                   n_i, steps, key, cg, ci, trips)
 
-            v = jax.vmap(client_fn, in_axes=(None, 0, 0, 0, 0, 0, None, 0))
-            n_lead = 8
+            v = jax.vmap(client_fn,
+                         in_axes=(None, 0, 0, 0, 0, 0, None, 0, None))
+            n_lead, steps_at = 8, 4
+
+        def cohort(*lead):
+            return v(*lead, jnp.max(lead[steps_at]))
+
         if not flat_updates:
-            return jax.jit(v)
+            return jax.jit(cohort)
 
         # Update-plane mode: the trained cohort never leaves the device —
         # inside the same jitted program each [K, ...] output leaf lands in
@@ -246,7 +252,7 @@ class CohortTrainer:
         # concatenated [K, W] intermediate.
         def cohort_flat(*args):
             lead, (buffer, row_ids) = args[:n_lead], args[n_lead:]
-            out_params, ci_new, losses = v(*lead)
+            out_params, ci_new, losses = cohort(*lead)
             with jax.named_scope("cohort.scatter_rows"):
                 buffer = scatter_rows(buffer, row_ids,
                                       jax.tree.leaves(out_params))
@@ -275,10 +281,10 @@ class CohortTrainer:
         self._key, sub = jax.random.split(self._key)
         return jax.random.split(sub, Kp)
 
-    def _compiled(self, cache_key, max_steps, flat_updates, indexed):
+    def _compiled(self, cache_key, flat_updates, indexed):
         if cache_key not in _COMPILE_CACHE:
             _COMPILE_CACHE[cache_key] = self._make_fn(
-                max_steps, flat_updates=flat_updates, indexed=indexed)
+                flat_updates=flat_updates, indexed=indexed)
         return _COMPILE_CACHE[cache_key]
 
     def _config_key(self) -> tuple:
@@ -313,13 +319,12 @@ class CohortTrainer:
             X, y = padt(np.asarray(X)), padt(np.asarray(y))
             n_i = padt(np.asarray(n_i))
             steps = np.concatenate([steps, np.zeros(Kp - K, steps.dtype)])
-        max_steps = _steps_bucket(int(steps.max()))
+        trips = int(steps.max())
         with obs.span("fl.train", round=round_, lanes=Kp, clients=K,
-                      steps=max_steps):
-            cache_key = self._config_key() + (Kp, max_steps, X.shape[1:],
-                                              y.dtype, flat_updates, "host")
-            fn = self._compiled(cache_key, max_steps, flat_updates,
-                                indexed=False)
+                      steps=trips):
+            cache_key = self._config_key() + (Kp, X.shape[1:], y.dtype,
+                                              flat_updates, "host")
+            fn = self._compiled(cache_key, flat_updates, indexed=False)
             keys = self._cohort_keys(Kp)
             c_global, c_clients = self._pad_variates(global_params, c_global,
                                                      c_clients, Kp, K)
@@ -328,8 +333,8 @@ class CohortTrainer:
             lead = (global_params, jnp.asarray(X), jnp.asarray(y),
                     jnp.asarray(n_i), jnp.asarray(steps), keys, c_global,
                     c_clients)
-            return self._run(fn, lead, update_sink, K, Kp, max_steps,
-                             steps, round_)
+            return self._run(fn, lead, update_sink, K, Kp, trips, steps,
+                             round_)
 
     def train_cohort_indexed(self, global_params: Pytree, store,
                              selection, n_i: np.ndarray, steps: np.ndarray,
@@ -341,8 +346,9 @@ class CohortTrainer:
         gathered out of the resident buffers inside the jit — zero H2D
         training-input bytes. Pad lanes repeat the last index (mirroring
         the host path's row repeat) and run 0 active steps. The compile
-        cache collapses to (cohort bucket, step bucket, flat_updates):
-        data shapes are fixed for the store's lifetime."""
+        cache collapses to (cohort bucket, flat_updates): data shapes are
+        fixed for the store's lifetime, and the step count is read on the
+        device."""
         flat_updates = update_sink is not None
         sel = np.asarray(selection, np.int32)
         n_i = np.asarray(n_i)
@@ -352,59 +358,58 @@ class CohortTrainer:
             sel = np.concatenate([sel, np.repeat(sel[-1:], Kp - K)])
             n_i = np.concatenate([n_i, np.repeat(n_i[-1:], Kp - K)])
             steps = np.concatenate([steps, np.zeros(Kp - K, steps.dtype)])
-        max_steps = _steps_bucket(int(steps.max()))
+        trips = int(steps.max())
         with obs.span("fl.train", round=round_, lanes=Kp, clients=K,
-                      steps=max_steps):
-            cache_key = self._config_key() + (Kp, max_steps,
-                                              store.X.shape[1:],
+                      steps=trips):
+            cache_key = self._config_key() + (Kp, store.X.shape[1:],
                                               store.y.dtype, flat_updates,
                                               "device")
-            fn = self._compiled(cache_key, max_steps, flat_updates,
-                                indexed=True)
+            fn = self._compiled(cache_key, flat_updates, indexed=True)
             keys = self._cohort_keys(Kp)
             c_global, c_clients = self._pad_variates(global_params, c_global,
                                                      c_clients, Kp, K)
             lead = (global_params, jnp.asarray(sel), jnp.asarray(n_i),
                     jnp.asarray(steps), keys, c_global, c_clients,
                     store.X, store.y)
-            return self._run(fn, lead, update_sink, K, Kp, max_steps,
-                             steps, round_)
+            return self._run(fn, lead, update_sink, K, Kp, trips, steps,
+                             round_)
 
-    def cohort_fn_indexed(self, store, K: int, max_steps_raw: int):
-        """The compiled indexed-flat cohort fn for a fixed (K, step-budget)
-        regime -> ``(fn, Kp, max_steps)``. Same cache key construction as
-        ``train_cohort_indexed`` with ``flat_updates=True``, so the fused
-        round megastep (``core.megastep``) calls through the IDENTICAL
-        compiled entry the stepwise path dispatches — jit-in-jit inlines it
-        into the scan body with the same traced ops."""
+    def cohort_fn_indexed(self, store, K: int, max_steps: int):
+        """The compiled indexed-flat cohort fn for ``K`` clients whose
+        longest runs ``max_steps`` steps -> ``(fn, Kp, max_steps)``; one fn
+        serves every step count of the lane bucket. Same cache key
+        construction as ``train_cohort_indexed`` with
+        ``flat_updates=True``, so the fused round megastep
+        (``core.megastep``) calls through the IDENTICAL compiled entry the
+        stepwise path dispatches — jit-in-jit inlines it into the scan body
+        with the same traced ops."""
         Kp = _bucket(K, self.cohort_floor)
-        max_steps = _steps_bucket(int(max_steps_raw))
-        cache_key = self._config_key() + (Kp, max_steps, store.X.shape[1:],
+        cache_key = self._config_key() + (Kp, store.X.shape[1:],
                                           store.y.dtype, True, "device")
-        fn = self._compiled(cache_key, max_steps, flat_updates=True,
-                            indexed=True)
-        return fn, Kp, max_steps
+        fn = self._compiled(cache_key, flat_updates=True, indexed=True)
+        return fn, Kp, int(max_steps)
 
-    def count_lane_steps(self, params: Pytree, Kp: int, K: int,
-                         max_steps: int, useful: int, rounds: int = 1) -> None:
-        """Count ``rounds`` dispatches of ``K`` clients on ``Kp`` lanes of
-        ``max_steps`` steps, ``useful`` of whose steps belong to a real
-        client's own step count: ``train.lane_steps`` (every lane-step the
-        program runs), ``train.real_lane_steps`` (those of real clients),
+    def count_lane_steps(self, params: Pytree, Kp: int, K: int, trips: int,
+                         useful: int) -> None:
+        """Count dispatches of ``K`` clients on ``Kp`` lanes whose loops ran
+        ``trips`` steps in all (each dispatch loops to its longest
+        client), ``useful`` of whose steps belong to a real client's own
+        step count: ``train.lane_steps`` (every lane-step the program
+        runs), ``train.real_lane_steps`` (those of real clients),
         ``train.useful_steps``, and, where the optimizer's kernel knows
         its traffic (``Optimizer.step_bytes``: the fused Adam kernel),
         ``train.adam_bytes``, the HBM bytes of its updates."""
-        lane_steps = rounds * Kp * max_steps
+        lane_steps = Kp * trips
         obs.count("train.lane_steps", lane_steps)
-        obs.count("train.real_lane_steps", rounds * K * max_steps)
+        obs.count("train.real_lane_steps", K * trips)
         obs.count("train.useful_steps", int(useful))
         if self.opt.step_bytes is not None:
             n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
             obs.count("train.adam_bytes", lane_steps * self.opt.step_bytes(n))
 
-    def _run(self, fn, lead, update_sink, K, Kp, max_steps, steps, round_):
+    def _run(self, fn, lead, update_sink, K, Kp, trips, steps, round_):
         """Launch a padded cohort; the losses are its one blocking read."""
-        self.count_lane_steps(lead[0], Kp, K, max_steps, int(steps.sum()))
+        self.count_lane_steps(lead[0], Kp, K, trips, int(steps.sum()))
         trim = lambda t: jax.tree.map(lambda a: a[:K], t)
         if update_sink is None:
             out_params, ci_new, losses = fn(*lead)

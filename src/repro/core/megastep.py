@@ -67,7 +67,7 @@ from repro.core.fleet_store import IDLE
 Pytree = Any
 
 #: compiled fused-scan programs, keyed by their static closure values
-#: (the trainer fn identity pins model/optimizer/Kp/max_steps; jax.jit
+#: (the trainer fn identity pins model/optimizer/Kp; jax.jit
 #: adds its own shape/dtype specialization on top)
 _SCAN_CACHE: dict[tuple, Any] = {}
 
@@ -81,7 +81,9 @@ class MegastepPlan:
     Kp: int                 # padded cohort bucket
     top: int                # free-stack height at entry
     fn: Any                 # compiled indexed-flat cohort fn
-    max_steps: int          # static step bucket baked into ``fn``
+    max_steps: int          # longest step budget among the slots: the most
+    #                         any fused round loops (each loops to its own
+    #                         cohort's longest client, read in ``fn``)
     sparse: bool            # aggregation dispatch (rows_dispatch)
     use_pallas: bool
     interpret: bool
@@ -428,9 +430,10 @@ def run_megastep(sched, plan: MegastepPlan) -> None:
         sel_np = np.asarray(ys[0])      # [R, K] selected slots
         ids_np = np.asarray(ys[1])      # [R, Kp] update rows
         losses_np = np.asarray(ys[2])   # [R, K]
-    sched.trainer.count_lane_steps(sched.params, Kp, K, plan.max_steps,
-                                   int(plan.steps_col[sel_np].sum()),
-                                   rounds=R)
+    steps_np = plan.steps_col[sel_np]   # [R, K]
+    sched.trainer.count_lane_steps(sched.params, Kp, K,
+                                   int(steps_np.max(axis=1).sum()),
+                                   int(steps_np.sum()))
 
     # ---- host replay: the real code paths, no device work ----------------
     from repro.core.services import RoundLog, _Payload

@@ -152,7 +152,7 @@ def test_solo_dispatch_pads_to_two_not_eight(data, model):
         params, store, [3], data.n[[3]], np.array([1], np.int64))
     new_keys = [k for k in _COMPILE_CACHE if k not in before]
     assert len(new_keys) == 1
-    kp = new_keys[0][6]        # config key (6 fields) + (Kp, max_steps, ...)
+    kp = new_keys[0][6]        # config key (6 fields) + (Kp, data shape, ...)
     assert kp == 2
     assert jax.tree.leaves(out)[0].shape[0] == 1 and losses.shape == (1,)
     n_before = len(_COMPILE_CACHE)

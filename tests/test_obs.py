@@ -4,11 +4,12 @@ Spans and counts cost one check while the profiler is off; while it
 records, spans land on the profiler's host plane, where the benchmark's
 trace reduction (``chipbench/benchlib/traces.py``) finds them by name, and
 counts land in the session tally. The trainer's lane-step counters match
-the buckets its dispatches ran, on the stepwise and on the fused megastep
-path, and the cohort program's named scopes rename nothing the benchmark
+the lane-steps its dispatches ran (lanes × the cohort's longest client),
+on the stepwise and on the fused megastep path, and the cohort program's named scopes rename nothing the benchmark
 matches.
 """
 import contextlib
+import dataclasses
 import os
 import re
 import sys
@@ -20,7 +21,7 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.core.client import CohortTrainer, _bucket, _steps_bucket
+from repro.core.client import CohortTrainer, _bucket
 from repro.core.controller import FLConfig
 from repro.core.scheduler import Scheduler
 from repro.faas.hardware import paper_fleet
@@ -68,7 +69,7 @@ def _record_dispatches(eng) -> list:
 def _expected(dispatches, floor) -> dict:
     want = dict.fromkeys(LANE_COUNTERS, 0)
     for K, steps in dispatches:
-        S = _steps_bucket(int(steps.max()))
+        S = int(steps.max())
         want["train.lane_steps"] += _bucket(K, floor) * S
         want["train.real_lane_steps"] += K * S
         want["train.useful_steps"] += int(steps.sum())
@@ -196,6 +197,28 @@ def test_fused_megastep_counts_what_stepwise_counts(data, model, tmp_path):
     assert counted["fused"]["train.lane_steps"] > 0
 
 
+def test_fused_megastep_counts_each_rounds_longest_client(data, model,
+                                                         tmp_path):
+    """Clients of 2 to 4 steps: the fused scan and the stepwise run of the
+    same rounds both count lanes × each round's longest client."""
+    het = dataclasses.replace(
+        data, n=np.array([20, 15, 15, 15, 15, 10, 10, 10, 10, 10]))
+    counted = {}
+    for mode in ("stepwise", "fused"):
+        eng = Scheduler(FLConfig(**megastep_cfg(megastep=mode)), model, het,
+                        det_fleet(N_CLIENTS))
+        seen = _record_dispatches(eng)
+        with recording(tmp_path / mode):
+            m = eng.run()
+        counted[mode] = {k: obs.session()[k] for k in LANE_COUNTERS}
+        if mode == "stepwise":
+            want = _expected(seen, eng.trainer.cohort_floor)
+        else:
+            assert m["megastep_rounds"] >= 5
+    assert counted["stepwise"] == counted["fused"] == want
+    assert want["train.useful_steps"] < want["train.real_lane_steps"]
+
+
 @pytest.mark.parametrize("path,counts", [("fused", True), ("xla", False)])
 def test_adam_bytes_count_the_fused_kernel(monkeypatch, model, path, counts,
                                           tmp_path):
@@ -204,7 +227,7 @@ def test_adam_bytes_count_the_fused_kernel(monkeypatch, model, path, counts,
     params = model.init(jax.random.PRNGKey(0))[0]
     n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params))
     with recording(tmp_path):
-        tr.count_lane_steps(params, Kp=8, K=5, max_steps=16, useful=40)
+        tr.count_lane_steps(params, Kp=8, K=5, trips=16, useful=40)
     got = obs.session()
     assert got["train.lane_steps"] == 128
     assert got["train.real_lane_steps"] == 80
@@ -279,7 +302,7 @@ def test_named_scopes_add_no_operation(data, model, monkeypatch, v5e_chip):
             on_chip((Kp,), jnp.int32))
 
     def names():
-        fn = tr._make_fn(8, flat_updates=True, indexed=True)
+        fn = tr._make_fn(flat_updates=True, indexed=True)
         hlo = fn.lower(*args).compile().as_text()
         return (hlo.split(",", 1)[0], _HLO_COMP.findall(hlo),
                 _HLO_INSTR.findall(hlo))
